@@ -7,12 +7,15 @@ Two legs, both doubling as CI smoke checks:
   unsharded engine under a trivial topology; raises otherwise.  Warm
   wall-time of the sharded scan is reported next to the unsharded engine's
   so the shard_map wrapper's overhead is visible.
-* **Scaling (subprocess)** — re-runs the same campaign under
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (default 8) so
-  the scan actually executes across N shards, and reports slot-UEs/s plus
-  the per-shard UE count.  On the 2-core CI container the forced shards
-  oversubscribe the same cores — the number demonstrates the path works
-  and what it costs there, not accelerator scaling.
+* **Scaling** — runs the same campaign across several shards and reports
+  slot-UEs/s plus the per-shard UE count.  On the CPU backend that is a
+  subprocess with ``JAX_PLATFORMS=cpu`` and
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (default 8): the
+  forced shards oversubscribe the same cores, so the number demonstrates
+  the path works and what it costs there, not accelerator scaling.  On an
+  accelerator the leg runs in this process on its own devices (a child
+  could not open a chip this process holds), and is skipped with a note
+  when the process has only one device.
 
 Invoked as a module (``python -m benchmarks.bench_sharded --child ...``)
 it runs the scaling leg and prints one JSON line (the parent parses it).
@@ -94,6 +97,29 @@ def _child(n_slots: int, n_ues: int) -> dict:
     }
 
 
+def _forced_host_child(n_slots: int, n_ues: int, forced_shards: int) -> dict:
+    """The scaling leg on ``forced_shards`` CPU devices, in a child process
+    (``XLA_FLAGS`` must precede jax initialization).  The child is pinned
+    to the CPU backend, so it never reaches for an accelerator."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={forced_shards} "
+        + env.get("XLA_FLAGS", "")
+    ).strip()
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_sharded", "--child",
+         "--n-slots", str(n_slots), "--n-ues", str(n_ues)],
+        env=env, capture_output=True, text=True, timeout=540,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"forced-{forced_shards}-shard child failed:\n{proc.stderr[-3000:]}"
+        )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def run(n_slots: int = 16, n_ues: int = 8, forced_shards: int = 8) -> dict:
     cfg, engine, topo = _build(n_ues)
     fn, args, sched, modes = _sharded_callable(
@@ -126,26 +152,20 @@ def run(n_slots: int = 16, n_ues: int = 8, forced_shards: int = 8) -> dict:
     print(f"1-device sharded:  {rate_1dev:8.1f} slot-UEs/s warm "
           f"(unsharded engine {n_slots * n_ues / unsharded_warm:8.1f})")
 
-    # -- scaling: forced multi-device mesh in a subprocess ------------------
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={forced_shards} "
-        + env.get("XLA_FLAGS", "")
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharded", "--child",
-         "--n-slots", str(n_slots), "--n-ues", str(n_ues)],
-        env=env, capture_output=True, text=True, timeout=540,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"forced-{forced_shards}-shard child failed:\n{proc.stderr[-3000:]}"
-        )
-    forced = json.loads(proc.stdout.strip().splitlines()[-1])
-    print(f"forced {forced['n_shards']} shards: "
-          f"{forced['slot_ues_per_s']:8.1f} slot-UEs/s warm "
-          f"({forced['ues_per_shard']} UEs/shard; CPU cores shared)")
+    # -- scaling: several shards ---------------------------------------------
+    if jax.default_backend() == "cpu":
+        forced = _forced_host_child(n_slots, n_ues, forced_shards)
+        print(f"forced {forced['n_shards']} shards: "
+              f"{forced['slot_ues_per_s']:8.1f} slot-UEs/s warm "
+              f"({forced['ues_per_shard']} UEs/shard; CPU cores shared)")
+    elif len(jax.devices()) > 1:
+        forced = _child(n_slots, n_ues)
+        print(f"{forced['n_shards']} {jax.devices()[0].device_kind} shards: "
+              f"{forced['slot_ues_per_s']:8.1f} slot-UEs/s warm "
+              f"({forced['ues_per_shard']} UEs/shard)")
+    else:
+        forced = None
+        print("scaling leg skipped: it needs more than one device")
     return {
         "parity": "bitwise",
         "one_device_slot_ues_per_s": rate_1dev,

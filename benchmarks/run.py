@@ -88,12 +88,13 @@ def _json_payload(outs: dict) -> dict:
         payload["session_slot_ues_per_s"] = session["session_slot_ues_per_s"]
     sharded = outs.get("sharded")
     if sharded:
+        forced = sharded["forced"] or {}
         payload["sharded"] = {
             "parity": sharded["parity"],
             "one_device_slot_ues_per_s":
                 sharded["one_device_slot_ues_per_s"],
-            "forced_shards": sharded["forced"]["n_shards"],
-            "forced_slot_ues_per_s": sharded["forced"]["slot_ues_per_s"],
+            "forced_shards": forced.get("n_shards"),
+            "forced_slot_ues_per_s": forced.get("slot_ues_per_s"),
         }
     streaming = outs.get("streaming")
     if streaming:
@@ -162,6 +163,10 @@ def main() -> None:
                     help="write a machine-readable perf snapshot")
     ap.add_argument("--dryrun-json", default="dryrun_results.json")
     args = ap.parse_args()
+
+    from repro.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     if args.smoke:
         # must precede the benchmarks.common import (module-level env reads)

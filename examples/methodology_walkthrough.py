@@ -11,6 +11,7 @@ Walks the full pipeline on the channel-estimation case study:
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.methodology import (
     design_policy_inputs,
     monotonicity_filter,
@@ -23,6 +24,7 @@ from repro.phy.scenario import GOOD
 
 
 def main():
+    enable_compilation_cache()
     cfg = SlotConfig(n_prb=24)
     net = AiEstimatorConfig(channels=8, n_res_blocks=1)
     pipe = PuschPipeline(cfg, init_params(jax.random.PRNGKey(0), cfg, net), net=net)

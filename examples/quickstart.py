@@ -77,6 +77,7 @@ import os
 
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.runtime import suggest_gated_capacity
 from repro.core.session import (
     ArchesSession,
@@ -588,6 +589,7 @@ def main():
                     help="demo the resident campaign service "
                          "(submit -> poll -> drain over HTTP)")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     print("registered scenarios:", ", ".join(scenario_names()), "\n")
     closed_loop_demo(max(args.n_ues, 2))

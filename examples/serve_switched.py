@@ -26,6 +26,7 @@ import urllib.request
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.session import CampaignSpec, PolicySpec, SwitchSpec, spec_hash
 from repro.models.config import get_config
 from repro.models.model import Model
@@ -151,6 +152,7 @@ def service_demo() -> None:
 
 
 def main():
+    enable_compilation_cache()
     expert_bank_demo()
     service_demo()
 

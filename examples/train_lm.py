@@ -13,6 +13,7 @@ import argparse
 import jax
 
 from repro.checkpoint.store import CheckpointManager
+from repro.compile_cache import enable_compilation_cache
 from repro.data.tokens import TokenStream
 from repro.models.config import get_config
 from repro.models.model import Model
@@ -21,6 +22,7 @@ from repro.train.step import TrainConfig, init_train_state, train_step
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--steps", type=int, default=60)

@@ -149,7 +149,7 @@ def policy_infer(
 
     ``backend`` selects the tree evaluator: ``"pallas"`` runs the
     ``tree_infer`` MXU kernel, ``"ref"`` the vectorized literal walk, and
-    ``"auto"`` picks pallas on TPU with the ref path as the CPU fallback.
+    ``"auto"`` picks the kernel on a TPU and the ref walk off the chip.
     Both are bitwise-equivalent (the kernel's one-hot feature gather is an
     exact matmul); the kernel tests assert it.  ``prev_mode`` only matters
     for the threshold policy's keep-band.

@@ -188,8 +188,9 @@ class ExpertBank:
         self.gated_capacity = gated_capacity
         #: optional fused hot path for GATED: ``(idx, src, base, *inputs) ->
         #: selected`` replaces the gather / expert-fn / scatter triple with
-        #: one kernel (``repro.kernels.gated_expert``).  Must be
-        #: bitwise-equal to the unfused composition.
+        #: one kernel (``repro.kernels.gated_expert``).  Must keep every
+        #: non-served UE's baseline bitwise and compute the same expert
+        #: (to f32 rounding) for served ones.
         self.gated_fused_apply = gated_fused_apply
         #: optional in-scan accuracy audit for GATED: per-UE NMSE of the
         #: gated expert's output vs the fail-safe baseline; UEs whose NMSE

@@ -108,10 +108,10 @@ class ExpertBankSpec:
     study switching, not estimator quality; pass trained params to
     ``ArchesSession(ai_params=...)`` to override).
 
-    ``fused=True`` (gated banks) runs the compact -> folded-GEMM -> scatter
-    hot path as one kernel (``repro.kernels.gated_expert``) — bitwise-equal
-    to the unfused triple, just fewer launches and no materialized
-    sub-batch.  ``dtype`` selects the AI expert's GEMM operand precision
+    ``fused=True`` (gated banks) runs the compact -> expert -> scatter hot
+    path as one kernel (``repro.kernels.gated_expert``) on a TPU — the same
+    expert to f32 rounding, with fewer launches and no materialized
+    sub-batch; off the chip it runs the unfused triple.  ``dtype`` selects the AI expert's GEMM operand precision
     (``"float32"`` — bitwise baseline — or ``"bfloat16"``), and
     ``audit_nmse_threshold`` arms the in-scan accuracy audit: a served
     UE whose gated output diverges from the fail-safe baseline by more

@@ -44,7 +44,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 UE_AXIS = "ues"
@@ -328,7 +328,7 @@ def open_loop_fn(
         in_specs=(P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS), P(None, UE_AXIS),
                   P(UE_AXIS), P()) + extra_specs,
         out_specs=(P(UE_AXIS), P(None, UE_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -413,7 +413,7 @@ def closed_loop_fn(
         in_specs=(P(UE_AXIS), P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS),
                   _policy_spec(policy), P(UE_AXIS), P()) + extra_specs,
         out_specs=(P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -465,7 +465,7 @@ def streaming_open_loop_fn(
         in_specs=(P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS), P(None, UE_AXIS),
                   P(UE_AXIS), P(), P(), P(UE_AXIS)) + extra_specs,
         out_specs=(P(UE_AXIS), P(None, UE_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -512,7 +512,7 @@ def streaming_closed_loop_fn(
                   _policy_spec(policy), P(UE_AXIS), P(), P(), P(UE_AXIS))
                  + extra_specs,
         out_specs=(P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -606,7 +606,7 @@ def run_perturbed_sharded(
             in_specs=(P(UE_AXIS), P(UE_AXIS), P(UE_AXIS), P(None, UE_AXIS),
                       P(UE_AXIS), P()),
             out_specs=(P(UE_AXIS), P(None, UE_AXIS)),
-            check_rep=False,
+            check_vma=False,
         )
 
     fn = _cached_jit(topo, (engine, "perturbed", profile, sharded), build)

@@ -4,7 +4,8 @@ Literally the unfused composition the kernel replaces — gather the selected
 UEs' inputs to a compact sub-batch, run the folded-GEMM expert, scatter the
 results back over the baseline — built from the exact same jnp ops as
 ``ExpertBank._run_gated``'s unfused path, so bitwise equality with it holds
-by construction (this is the CPU fallback, not just a test oracle).
+by construction.  It is what ``backend="auto"`` runs off the chip (CPU
+runs and tests); on a TPU ``auto`` always runs the compiled kernel.
 """
 
 from __future__ import annotations

@@ -88,4 +88,5 @@ def mmse_interp_2d(
         out_specs=[o_spec, o_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
+        name="mmse_interp",
     )(h_real, h_imag, w_real, w_imag)

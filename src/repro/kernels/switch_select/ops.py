@@ -190,7 +190,7 @@ def switch_scatter(src, compact, designated, *, backend: str = "auto"):
       designated: structurally identical pytree of ``(n_ues, ...)`` leaves,
         aliased to the output on the kernel path.
       backend: ``"pallas"`` (TPU kernel), ``"ref"`` (pure-jnp gather/select)
-        or ``"auto"`` — pallas on TPU, ref as the CPU fallback.  Both are
+        or ``"auto"`` — the kernel on a TPU, ref off the chip.  Both are
         bitwise-equal by construction: neither path does arithmetic on the
         payload.
 

@@ -133,6 +133,7 @@ def switch_select_2d(
         out_shape=jax.ShapeDtypeStruct((rows, cols), designated.dtype),
         input_output_aliases={2: 0},  # designated buffer -> output (zero-gap)
         interpret=interpret,
+        name="switch_select",
     )(mode, alternatives, designated)
 
 
@@ -233,6 +234,7 @@ def switch_select_batched_2d(
         out_shape=jax.ShapeDtypeStruct((n_ues, rows, cols), designated.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="switch_select_batched",
     )(modes, alternatives, designated)
 
 
@@ -338,4 +340,5 @@ def switch_gather_batched_2d(
         out_shape=jax.ShapeDtypeStruct((n_ues, rows, cols), designated.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="switch_gather_batched",
     )(src, compact, designated)

@@ -31,7 +31,12 @@ DEFAULT_BLOCK_B = 256
 
 def _tree_kernel(x_ref, t_ref, thr_ref, a_ref, b_ref, non_ref, leaf_ref, out_ref):
     x = x_ref[...]
-    proj = jnp.dot(x, t_ref[...], preferred_element_type=jnp.float32)
+    # the one-hot gather must reproduce x bit for bit, so it asks for full
+    # f32 contraction; the 0/1 count matmuls below are exact at any precision
+    proj = jnp.dot(
+        x, t_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     d = (proj > thr_ref[...]).astype(jnp.float32)
     count = jnp.dot(d, a_ref[...], preferred_element_type=jnp.float32) + jnp.dot(
         1.0 - d, b_ref[...], preferred_element_type=jnp.float32
@@ -76,4 +81,5 @@ def tree_infer_2d(
         out_specs=pl.BlockSpec((block_b, nl), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, nl), jnp.float32),
         interpret=interpret,
+        name="tree_infer",
     )(x, t, thr, a, b, n_on, leaf_vals)

@@ -38,6 +38,10 @@ def main(argv=None) -> int:
                    help="append segment telemetry to this JSONL file")
     args = p.parse_args(argv)
 
+    from repro.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
+
     from repro.service.api import ServiceAPI
     from repro.service.exporters import JsonlExporter
     from repro.service.service import CampaignService

@@ -33,8 +33,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.distributed.compression import compress_decompress, init_error_feedback
 from repro.distributed.sharding import make_rules, spec
 
-SINGLE = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 RULES = make_rules()
 
 

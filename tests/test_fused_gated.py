@@ -1,11 +1,15 @@
-"""Fused gated hot path: one kernel == the unfused triple, bitwise.
+"""Fused gated hot path: one kernel == the unfused triple.
 
 Three layers of the tentpole contract:
 
-* **kernel** — ``gated_expert_apply`` (Pallas interpret mode and the jnp
-  reference backend) matches the unfused gather -> folded-GEMM -> scatter
-  composition bitwise across the gating edge cases: all-AI, all-MMSE,
-  U == 1, odd U, capacity 1, exact-capacity boundary, padding rows.
+* **kernel** — ``gated_expert_apply`` matches the unfused gather ->
+  folded-GEMM -> scatter composition across the gating edge cases:
+  all-AI, all-MMSE, U == 1, odd U, capacity 1, exact-capacity boundary,
+  padding rows.  The jnp reference backend is bitwise; the Pallas kernel
+  (interpret mode) is bitwise equal to its own forward in plain jnp
+  (``forward_2d_ref`` over the same views), batch-composition-free on
+  served UEs, and agrees with the XLA forward to f32 rounding (its 2-D
+  per-antenna GEMMs are what Mosaic compiles).
 * **bank** — the ``gated_fused_apply`` hook slots into ``ExpertBank`` (3+
   expert banks included) without changing any output or accounting leaf;
   the in-scan NMSE audit trips on divergent outputs (adversarial inputs,
@@ -37,6 +41,8 @@ from repro.core.expert_bank import ExecutionMode, Expert, ExpertBank
 from repro.core.runtime import BatchedRunHistory
 from repro.core.telemetry import physical_trajectory
 from repro.kernels.gated_expert import gated_expert_apply, gated_expert_apply_ref
+from repro.kernels.gated_expert import ops as gated_ops
+from repro.kernels.gated_expert.gated_expert import forward_2d_ref, kernel_params
 from repro.kernels.switch_select.ref import switch_gather_batched_tree_ref
 from repro.phy.ai_estimator import (
     AiEstimatorConfig,
@@ -95,7 +101,7 @@ def _gating(mode: np.ndarray, capacity: int):
     return jnp.asarray(idx), jnp.asarray(src)
 
 
-# -- kernel: fused == unfused composition, bitwise -----------------------------
+# -- kernel: fused == unfused composition -------------------------------------
 
 
 EDGE_CASES = [
@@ -110,8 +116,36 @@ EDGE_CASES = [
 ]
 
 
+#: f32 agreement of the kernel's per-antenna forward with the XLA path's
+#: batched GEMMs, relative to the largest output magnitude: the two sum the
+#: same products in different blockings (~1e-7 apart at this width)
+F32_REL_TOL = 1e-5
+
+
+def _forward_2d_twin(src, h_ls, des, folded):
+    """The fused kernel's result built in plain jnp: ``forward_2d_ref`` on
+    every served (UE, antenna) tile of the kernel's own views, the
+    baseline bytes everywhere else.  Run op by op, as interpret mode runs
+    the kernel body: under ``jit`` XLA's fusions round some sums one ulp
+    differently."""
+    n_sym, n_p = h_ls.shape[2:]
+    kp = kernel_params(folded)
+    x = gated_ops._ls_view(h_ls)
+    out = gated_ops._estimate_view(des, n_p)
+    for u in np.flatnonzero(np.asarray(src) >= 0):
+        for a in range(h_ls.shape[1]):
+            out = out.at[u, a].set(jnp.stack(forward_2d_ref(kp, x[u, a], n_p)))
+    return gated_ops._from_estimate_view(out, n_p, n_sym)
+
+
 @pytest.mark.parametrize("n_ues,capacity,mode", EDGE_CASES)
 def test_fused_kernel_matches_unfused_bitwise(folded, n_ues, capacity, mode):
+    """The ``ref`` backend is the unfused triple bitwise, and the Pallas
+    kernel (interpret mode) is its plain-jnp twin bitwise: kept UEs keep
+    their baseline bytes, served UEs get ``forward_2d_ref``'s.  Besides,
+    every served UE gets bitwise the bytes a capacity-1 call on that UE
+    alone gives (no batch dependence), and the kernel matches the unfused
+    XLA forward to f32 rounding."""
     h_ls, des = _mk_inputs(n_ues * 10 + capacity, n_ues)
     idx, src = _gating(np.asarray(mode, np.int32), capacity)
 
@@ -122,19 +156,31 @@ def test_fused_kernel_matches_unfused_bitwise(folded, n_ues, capacity, mode):
     ref = gated_expert_apply(idx, src, h_ls, des, folded, backend="ref")
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(want))
 
-    fused = gated_expert_apply(
+    fused = np.asarray(gated_expert_apply(
         idx, src, h_ls, des, folded, backend="pallas", interpret=True
+    ))
+    np.testing.assert_array_equal(
+        fused, np.asarray(_forward_2d_twin(src, h_ls, des, folded))
     )
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(want))
-
-    # non-vacuous: served UEs actually received the expert's output
+    want = np.asarray(want)
     served = np.flatnonzero(np.asarray(src) >= 0)
-    for u in served:
-        assert not np.array_equal(np.asarray(fused)[u], np.asarray(des)[u])
-    # kept UEs round-trip the baseline bytes untouched
     kept = np.flatnonzero(np.asarray(src) < 0)
-    for u in kept:
-        np.testing.assert_array_equal(np.asarray(fused)[u], np.asarray(des)[u])
+    # kept UEs round-trip the baseline bytes untouched
+    np.testing.assert_array_equal(fused[kept], np.asarray(des)[kept])
+    if served.size:
+        scale = np.abs(want[served]).max()
+        np.testing.assert_allclose(
+            fused[served], want[served], rtol=0, atol=F32_REL_TOL * scale
+        )
+    for u in served:
+        # non-vacuous: served UEs actually received the expert's output
+        assert not np.array_equal(fused[u], np.asarray(des)[u])
+        alone = gated_expert_apply(
+            jnp.asarray([u], jnp.int32),
+            jnp.where(jnp.arange(n_ues) == u, 0, -1).astype(jnp.int32),
+            h_ls, des, folded, backend="pallas", interpret=True,
+        )
+        np.testing.assert_array_equal(fused[u], np.asarray(alone)[u])
 
 
 @pytest.mark.parametrize("n_ues,capacity,mode", EDGE_CASES[:3])

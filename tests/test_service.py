@@ -610,12 +610,14 @@ def test_cancel_running_stops_at_boundary_and_keeps_checkpoint(
     st = svc.status(cid)
     assert st["segments_done"] == 1
     assert st["checkpoint_steps"] == [1]  # retained for a later resubmit
-    # cancelled campaigns are terminal: a restart does not resurrect them
+    # cancelled campaigns are terminal: a restart does not resurrect them.
+    # The first service stops before the restart: two live services on one
+    # state dir race on its status files.
+    assert svc.drain(timeout=30)
     svc2 = CampaignService(str(tmp_path / "svc"))
     svc2._recover()
     assert svc2.status(cid)["state"] == CampaignState.CANCELLED
     assert svc2._queue.qsize() == 0
-    assert svc.drain(timeout=30)
 
 
 def test_failed_campaign_reports_error(tmp_path):
