@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.closed_loop import (
     DevicePolicy,
     SwitchConfig,
@@ -565,30 +566,32 @@ class BatchedPuschPipeline:
     def _ue_pre(self, profile: TdlProfile, p: ChannelParams, snr_db, olla_db, key):
         """Link adaptation + TX + channel + LS for one UE (traced MCS)."""
         cfg = self.cfg
-        k_tx, k_ch, k_n, k_crc = jax.random.split(key, 4)
+        with tracing.stage(tracing.TX):
+            k_tx, k_ch, k_n, k_crc = jax.random.split(key, 4)
 
-        mcs_idx = select_mcs_index(snr_db + olla_db)
-        qm_idx = jnp.take(self._qm_idx_by_mcs, mcs_idx)
-        qm = jnp.take(self._qm_by_mcs, mcs_idx).astype(jnp.float32)
-        code_rate = jnp.take(self._rate_by_mcs, mcs_idx)
-        tbs = jnp.take(self._tbs_table, mcs_idx).astype(jnp.float32)
+            mcs_idx = select_mcs_index(snr_db + olla_db)
+            qm_idx = jnp.take(self._qm_idx_by_mcs, mcs_idx)
+            qm = jnp.take(self._qm_by_mcs, mcs_idx).astype(jnp.float32)
+            code_rate = jnp.take(self._rate_by_mcs, mcs_idx)
+            tbs = jnp.take(self._tbs_table, mcs_idx).astype(jnp.float32)
 
-        # TX for every supported modulation order; select per UE.  Bits are
-        # drawn once at the widest order and prefix-sliced, so the payload
-        # for a given (key, qm) is deterministic.
-        n_re = cfg.n_data_re()
-        bits = jax.random.bernoulli(k_tx, 0.5, (n_re * max(QM_VALUES),)).astype(
-            jnp.uint8
-        )
-        syms_all = jnp.stack(
-            [qam.modulate(bits[: n_re * q], q) for q in QM_VALUES], axis=0
-        )
-        syms = jnp.take(syms_all, qm_idx, axis=0)
-
-        tx_grid = dmrs_mod.map_slot_grid(cfg, syms, self._pilots)
-        fields = simulate_slot_channel_traced(k_ch, cfg, profile, p)
-        rx_grid = apply_channel(k_n, tx_grid, fields)
-        h_ls = ls_estimate(cfg, rx_grid, self._pilots)
+            # TX for every supported modulation order; select per UE.  Bits
+            # are drawn once at the widest order and prefix-sliced, so the
+            # payload for a given (key, qm) is deterministic.
+            n_re = cfg.n_data_re()
+            bits = jax.random.bernoulli(
+                k_tx, 0.5, (n_re * max(QM_VALUES),)
+            ).astype(jnp.uint8)
+            syms_all = jnp.stack(
+                [qam.modulate(bits[: n_re * q], q) for q in QM_VALUES], axis=0
+            )
+            syms = jnp.take(syms_all, qm_idx, axis=0)
+            tx_grid = dmrs_mod.map_slot_grid(cfg, syms, self._pilots)
+        with tracing.stage(tracing.CHANNEL):
+            fields = simulate_slot_channel_traced(k_ch, cfg, profile, p)
+            rx_grid = apply_channel(k_n, tx_grid, fields)
+        with tracing.stage(tracing.RX):
+            h_ls = ls_estimate(cfg, rx_grid, self._pilots)
         return {
             "mcs_idx": mcs_idx,
             "qm_idx": qm_idx,
@@ -605,9 +608,15 @@ class BatchedPuschPipeline:
     def _ue_post(self, link: DeviceLinkState, pre: dict, h_sel: jax.Array):
         """Equalize + KPMs + OLLA for one UE (scalar link-state leaves)."""
         cfg = self.cfg
-        x_hat, _ = mmse_equalize(cfg, pre["rx_grid"], h_sel, pre["noise_var"])
-        data_hat = dmrs_mod.extract_data_re(cfg, x_hat[None])[0]
+        with tracing.stage(tracing.RX):
+            x_hat, _ = mmse_equalize(cfg, pre["rx_grid"], h_sel, pre["noise_var"])
+            data_hat = dmrs_mod.extract_data_re(cfg, x_hat[None])[0]
+        with tracing.stage(tracing.KPM):
+            return self._ue_kpms(link, pre, h_sel, data_hat)
 
+    def _ue_kpms(self, link: DeviceLinkState, pre: dict, h_sel, data_hat):
+        """EVM, TB model, OLLA and the KPM report for one UE."""
+        cfg = self.cfg
         # decision-directed EVM per modulation order, selected by qm_idx
         # (per-axis PAM nearest — equivalent to the host pipeline's
         # constellation argmin on square Gray QAM, O(1) per symbol)
@@ -768,13 +777,15 @@ class BatchedPuschPipeline:
             # below.  With an all-ones mask every select is the identity, so
             # a fully-attached slot is bitwise-equal to the unmasked path.
             act = jnp.asarray(active)
-            modes = jnp.where(
-                act, jnp.asarray(modes, jnp.int32),
-                jnp.int32(self.bank.default_mode),
-            )
+            with tracing.stage(tracing.EXPERTS):
+                modes = jnp.where(
+                    act, jnp.asarray(modes, jnp.int32),
+                    jnp.int32(self.bank.default_mode),
+                )
             if cell_of_ue is not None:
                 # empty lanes must not contribute to the per-cell mean load
-                p = p._replace(interf_on=jnp.where(act, p.interf_on, 0.0))
+                with tracing.stage(tracing.CHANNEL):
+                    p = p._replace(interf_on=jnp.where(act, p.interf_on, 0.0))
         if cell_of_ue is not None:
             # multi-cell topology: fold per-cell offsets + inter-cell
             # coupling into this slot's per-UE knobs.  Under shard_map,
@@ -785,9 +796,10 @@ class BatchedPuschPipeline:
                     "cell coupling needs per-UE ChannelParams leaves; "
                     "broadcast_params_to_ues the schedule first"
                 )
-            p = apply_cell_coupling(
-                p, cell_of_ue, cell_params, axis_name=cell_axis
-            )
+            with tracing.stage(tracing.CHANNEL):
+                p = apply_cell_coupling(
+                    p, cell_of_ue, cell_params, axis_name=cell_axis
+                )
         if jnp.ndim(p.noise_var) == 1:
             # per-UE heterogeneous conditions: params carry a (U,) axis
             pre = jax.vmap(
@@ -798,8 +810,32 @@ class BatchedPuschPipeline:
                 lambda snr, olla, key: self._ue_pre(profile, p, snr, olla, key)
             )(link.reported_snr_db, link.olla_offset_db, keys)
         n_ues = keys.shape[0]
+        with tracing.stage(tracing.EXPERTS):
+            h_sel, extras = self._bank_core(pre["h_ls"], modes, keys, n_ues,
+                                            rho, faults, corrupt)
+        new_link, outputs = jax.vmap(self._ue_post)(link, pre, h_sel)
+        outputs.update(extras)
+        if active is not None:
+            # detached lanes: state frozen, every output/KPM leaf zeroed —
+            # they carry no throughput, no cost, no overflow, no telemetry
+            with tracing.stage(tracing.KPM):
+                new_link = jax.tree.map(
+                    lambda n, o: jnp.where(act, n, o), new_link, link
+                )
+                outputs = jax.tree.map(
+                    lambda x: jnp.where(
+                        act.reshape(act.shape + (1,) * (x.ndim - 1)),
+                        x, jnp.zeros_like(x),
+                    ),
+                    outputs,
+                )
+        return new_link, outputs
+
+    def _bank_core(self, h_ls, modes, keys, n_ues, rho, faults, corrupt):
+        """The expert bank on the slot's LS estimates: the selected estimate
+        per UE and the per-UE cost and fallback leaves."""
         if rho is None:
-            out = self.bank(jnp.asarray(modes, jnp.int32), pre["h_ls"])
+            out = self.bank(jnp.asarray(modes, jnp.int32), h_ls)
             h_sel = out.selected
             exec_flops = self.bank.executed_flops_per_ue(out)
             overflow = (
@@ -822,7 +858,7 @@ class BatchedPuschPipeline:
             # at node 2c — no switching, no AI in the loop.  ``rho`` is a
             # per-UE intensity vector, so one batched slot evaluates a whole
             # rho grid at once.
-            h_mmse = self._mmse_from_ls_batched(pre["h_ls"])
+            h_mmse = self._mmse_from_ls_batched(h_ls)
             pkeys = jax.vmap(lambda k: jax.random.fold_in(k, 0x9e7))(keys)
             h_sel = jax.vmap(perturb_estimate)(
                 h_mmse, jnp.asarray(rho, jnp.float32), pkeys
@@ -834,25 +870,12 @@ class BatchedPuschPipeline:
             overflow = jnp.zeros((n_ues,), jnp.int32)
             audit_tripped = jnp.zeros((n_ues,), jnp.int32)
             health_tripped = jnp.zeros((n_ues,), jnp.int32)
-        new_link, outputs = jax.vmap(self._ue_post)(link, pre, h_sel)
-        outputs["executed_flops"] = exec_flops
-        outputs["gated_overflow"] = overflow
-        outputs["audit_tripped"] = audit_tripped
-        outputs["health_tripped"] = health_tripped
-        if active is not None:
-            # detached lanes: state frozen, every output/KPM leaf zeroed —
-            # they carry no throughput, no cost, no overflow, no telemetry
-            new_link = jax.tree.map(
-                lambda n, o: jnp.where(act, n, o), new_link, link
-            )
-            outputs = jax.tree.map(
-                lambda x: jnp.where(
-                    act.reshape(act.shape + (1,) * (x.ndim - 1)),
-                    x, jnp.zeros_like(x),
-                ),
-                outputs,
-            )
-        return new_link, outputs
+        return h_sel, {
+            "executed_flops": exec_flops,
+            "gated_overflow": overflow,
+            "audit_tripped": audit_tripped,
+            "health_tripped": health_tripped,
+        }
 
     @partial(jax.jit, static_argnames=("self", "profile"))
     def slot_step(
@@ -1005,24 +1028,37 @@ class BatchedPuschPipeline:
         feed the circuit breaker last.  The ``quarantined`` leaf records
         the overlay as of the *start* of the slot.
         """
-        keys = jax.vmap(lambda k: jax.random.fold_in(k, slot_idx))(ue_keys)
+        with tracing.stage(tracing.TX):
+            keys = jax.vmap(lambda k: jax.random.fold_in(k, slot_idx))(ue_keys)
         committed = sw.active_mode
-        if faults is not None:
-            quarantined = (sw.quarantine > 0)
-            exec_modes = jnp.where(
-                quarantined, jnp.int32(sw_cfg.default_mode), committed
-            )
-            dv_s, cor_s, tv_s = fault_s
-        else:
-            quarantined = jnp.zeros_like(committed, bool)
-            exec_modes = committed
-            dv_s = cor_s = tv_s = None
+        with tracing.stage(tracing.DECIDE):
+            if faults is not None:
+                quarantined = (sw.quarantine > 0)
+                exec_modes = jnp.where(
+                    quarantined, jnp.int32(sw_cfg.default_mode), committed
+                )
+                dv_s, cor_s, tv_s = fault_s
+            else:
+                quarantined = jnp.zeros_like(committed, bool)
+                exec_modes = committed
+                dv_s = cor_s = tv_s = None
         link, out = self._slot_core(
             profile, link, exec_modes, keys, p,
             cell_of_ue=cell_of_ue, cell_params=cell_params,
             cell_axis=cell_axis, active=active,
             faults=faults, corrupt=cor_s,
         )
+        with tracing.stage(tracing.DECIDE):
+            new_sw, out = self._decide(
+                sw_cfg, policy, sw, slot_idx, out, committed, quarantined,
+                active, faults, dv_s, tv_s,
+            )
+        return link, new_sw, out
+
+    def _decide(self, sw_cfg, policy, sw, slot_idx, out, committed,
+                quarantined, active, faults, dv_s, tv_s):
+        """The slot's KPMs into the window, the policy's decision, and the
+        register, boundary and breaker updates for the next slot."""
         vecs = trajectory_kpm_matrix(out["kpms"], sw_cfg.feature_names)
         decide = (
             True
@@ -1066,7 +1102,7 @@ class BatchedPuschPipeline:
                 pending_mode=jnp.where(act, out["pending_mode"], 0),
                 quarantined=jnp.where(act, out["quarantined"], 0),
             )
-        return link, new_sw, out
+        return new_sw, out
 
     @partial(jax.jit, static_argnames=(
         "self", "profile", "sw_cfg", "cell_axis", "faults"
@@ -1118,16 +1154,17 @@ class BatchedPuschPipeline:
             fault_masks=fault_masks,
         )
 
-    @partial(jax.jit, static_argnames=("self", "profile", "sw_cfg", "faults"))
     def _closed_slot_step(
         self, profile, sw_cfg, link, sw, slot_idx, ue_keys, p, policy,
         fault_s=None, *, faults=None,
     ):
-        """One compiled closed-loop slot (python-loop debug/benchmark path)."""
-        return self._closed_step(
-            profile, sw_cfg, policy, ue_keys, link, sw, slot_idx, p,
-            faults=faults, fault_s=fault_s,
-        )
+        """One compiled closed-loop slot (python-loop debug/benchmark path),
+        called under the ``arches.slot.dispatch`` host span."""
+        with jax.profiler.TraceAnnotation(tracing.SLOT_DISPATCH):
+            return _closed_slot_step(
+                self, profile, sw_cfg, link, sw, slot_idx, ue_keys, p,
+                policy, fault_s, faults=faults,
+            )
 
     def run_closed_loop(
         self,
@@ -1281,3 +1318,16 @@ class BatchedPuschPipeline:
             outs.append(out)
         traj = jax.tree.map(lambda *ls: jnp.stack(ls, 0), *outs)
         return link, traj
+
+
+@partial(jax.jit, static_argnames=("self", "profile", "sw_cfg", "faults"))
+def _closed_slot_step(
+    self, profile, sw_cfg, link, sw, slot_idx, ue_keys, p, policy,
+    fault_s=None, *, faults=None,
+):
+    """The compiled body of ``BatchedPuschPipeline._closed_slot_step``; the
+    profiler names its program ``jit__closed_slot_step``."""
+    return self._closed_step(
+        profile, sw_cfg, policy, ue_keys, link, sw, slot_idx, p,
+        faults=faults, fault_s=fault_s,
+    )

@@ -6,7 +6,10 @@ these kernels in interpret mode or as their jnp ``ref`` twins, which cannot
 show what Mosaic refuses (an unsupported shape cast, an unaligned slice, a
 tile that overflows VMEM).  Each case compiles one kernel at U = 16 UEs for
 the paper's 106-PRB cell or a 273-PRB (100 MHz at 30 kHz) carrier and
-checks that the compiled program holds the ``tpu_custom_call``.
+checks that the compiled program holds the ``tpu_custom_call``.  The whole
+closed-loop slot step, compiled at a small width, keeps its kernels' names
+and its six stage scopes (``repro.tracing``), which the benchmark's trace
+readers rely on.
 
 The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs this file loads the TPU library.  JAX's
@@ -16,6 +19,7 @@ compiled for a described chip cannot be read back without one.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,3 +138,51 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, n_prb):
     cfg = SlotConfig(n_prb=n_prb)
     text = KERNELS[kernel](cfg, lambda shape, dt: _spec(one_chip, shape, dt))
     assert "tpu_custom_call" in text, f"{kernel} lowered without a Mosaic kernel"
+
+
+@pytest.fixture(scope="module")
+def slot_step_text(one_chip):
+    """The closed-loop slot step (the program the benchmark drives),
+    compiled for one v5e chip at 24 PRB and 4 UEs.  ``jax.default_backend``
+    is steered to ``"tpu"`` while it is traced, so that the step takes its
+    Pallas kernels as it does on the chip.  JAX's trace caches are cleared
+    around it: a kernel traced earlier in this process for the CPU would
+    be reused in interpret mode, and this trace must not be reused by a
+    later CPU test."""
+    from test_tracing import _slot, step_inputs
+
+    from repro.phy import pipeline
+
+    x = step_inputs(SlotConfig(n_prb=24), n_ues=4)
+    spec = lambda tree: jax.tree.map(  # noqa: E731
+        lambda v: _spec(one_chip, np.shape(v), jnp.asarray(v).dtype), tree)
+    args = [spec(a) if i not in (0, 1) else a
+            for i, a in enumerate(_slot(x, x["params"], 3))]
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            jax.clear_caches()
+            lowered = pipeline._closed_slot_step.lower(x["eng"], *args)
+    finally:
+        jax.clear_caches()
+    return lowered.compile().as_text()
+
+
+def test_slot_step_keeps_its_kernel_names_for_v5e(slot_step_text):
+    """The benchmark's kernel readers match a Pallas kernel's instruction
+    by its name: the stage scopes must not rename them."""
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*"
+                         r'custom_call_target="tpu_custom_call"',
+                         slot_step_text, flags=re.M)
+    for name in ("mmse_interp", "tree_infer"):
+        assert any(k == name or k.startswith(name + ".") for k in kernels), (
+            name, kernels)
+
+
+def test_slot_step_carries_every_stage_scope_for_v5e(slot_step_text):
+    from repro import tracing
+
+    names = set(re.findall(r'op_name="([^"]*)"', slot_step_text))
+    for stage in tracing.STAGES:
+        assert any(re.search(rf"(?<![\w.]){re.escape(stage)}(?![\w.])", n)
+                   for n in names), stage
