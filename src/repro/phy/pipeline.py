@@ -619,7 +619,8 @@ class BatchedPuschPipeline:
         cfg = self.cfg
         # decision-directed EVM per modulation order, selected by qm_idx
         # (per-axis PAM nearest — equivalent to the host pipeline's
-        # constellation argmin on square Gray QAM, O(1) per symbol)
+        # constellation argmin on square Gray QAM, O(1) per symbol, picked
+        # from the table's per-axis values by selects, not gathers)
         dd_errs, sig_pows = [], []
         for q in QM_VALUES:
             nearest = qam.nearest_point(data_hat, q)

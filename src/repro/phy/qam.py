@@ -24,8 +24,8 @@ def _gray_pam_levels(bits_per_axis: int) -> np.ndarray:
 _NORM = {2: np.sqrt(2.0), 4: np.sqrt(10.0), 6: np.sqrt(42.0), 8: np.sqrt(170.0)}
 
 
-def constellation(qm: int) -> jax.Array:
-    """All 2**qm points in bit-label order (MSB first, I bits then Q bits)."""
+def _table(qm: int) -> np.ndarray:
+    """``constellation(qm)`` as a host array."""
     half = qm // 2
     pam = _gray_pam_levels(half)
     pts = np.zeros(1 << qm, np.complex128)
@@ -33,7 +33,12 @@ def constellation(qm: int) -> jax.Array:
         i_bits = label >> half
         q_bits = label & ((1 << half) - 1)
         pts[label] = pam[i_bits] + 1j * pam[q_bits]
-    return jnp.asarray(pts / _NORM[qm], jnp.complex64)
+    return (pts / _NORM[qm]).astype(np.complex64)
+
+
+def constellation(qm: int) -> jax.Array:
+    """All 2**qm points in bit-label order (MSB first, I bits then Q bits)."""
+    return jnp.asarray(_table(qm))
 
 
 @partial(jax.jit, static_argnames=("qm",))
@@ -43,7 +48,12 @@ def modulate(bits: jax.Array, qm: int) -> jax.Array:
     groups = bits.reshape(shape + (-1, qm))
     weights = jnp.asarray([1 << (qm - 1 - i) for i in range(qm)], jnp.int32)
     labels = jnp.sum(groups.astype(jnp.int32) * weights, axis=-1)
-    return jnp.take(constellation(qm), labels)
+    half = qm // 2
+    code_i, code_q = labels >> half, labels & ((1 << half) - 1)
+    lev = _axis_levels(qm)
+    return jax.lax.complex(
+        _pick(lev, code_i ^ (code_i >> 1)), _pick(lev, code_q ^ (code_q >> 1))
+    )
 
 
 @partial(jax.jit, static_argnames=("qm",))
@@ -74,13 +84,24 @@ def hard_bits(llr: jax.Array) -> jax.Array:
     return (llr < 0).astype(jnp.uint8)
 
 
-def _gray_inverse(bits_per_axis: int) -> np.ndarray:
-    """Natural PAM-level index -> per-axis bit code (inverse Gray map)."""
-    m = 1 << bits_per_axis
-    inv = np.zeros(m, np.int32)
-    for code in range(m):
-        inv[code ^ (code >> 1)] = code
-    return inv
+def _axis_levels(qm: int) -> np.ndarray:
+    """The per-axis values of ``constellation(qm)`` in natural (ascending) order.
+
+    Taken from the table itself, so they are bitwise its f32 components.
+    """
+    return np.unique(_table(qm).real)
+
+
+def _pick(levels: np.ndarray, idx: jax.Array) -> jax.Array:
+    """``levels[idx]`` for a static table of at most 16 values, as a select chain.
+
+    XLA lowers a ``take`` from a table to a per-element gather, which on the
+    TPU costs far more than these elementwise selects.
+    """
+    out = jnp.full(idx.shape, levels[0], jnp.float32)
+    for k in range(1, len(levels)):
+        out = jnp.where(idx >= k, levels[k], out)
+    return out
 
 
 @partial(jax.jit, static_argnames=("qm",))
@@ -89,15 +110,13 @@ def nearest_point(y: jax.Array, qm: int) -> jax.Array:
 
     Square Gray-mapped QAM factorizes: the closest point is the closest PAM
     level per I/Q axis, so this is O(1) per symbol instead of the O(2^qm)
-    distance argmin — same point (up to measure-zero midpoint ties), gathered
-    from the exact ``constellation`` table.  Used by the batched engine's
-    decision-directed EVM, which evaluates every supported modulation order
-    each slot.
+    distance argmin — same point (up to measure-zero midpoint ties), built
+    from the exact per-axis values of the ``constellation`` table without a
+    gather.  Used by the batched engine's decision-directed EVM, which
+    evaluates every supported modulation order each slot.
     """
-    half = qm // 2
-    m = 1 << half
-    pts = constellation(qm)
-    inv = jnp.asarray(_gray_inverse(half))
+    m = 1 << (qm // 2)
+    lev = _axis_levels(qm)
     scaled = y * _NORM[qm]
 
     def level_idx(x):
@@ -105,6 +124,7 @@ def nearest_point(y: jax.Array, qm: int) -> jax.Array:
             jnp.int32
         )
 
-    code_i = jnp.take(inv, level_idx(jnp.real(scaled)))
-    code_q = jnp.take(inv, level_idx(jnp.imag(scaled)))
-    return jnp.take(pts, code_i * m + code_q)
+    return jax.lax.complex(
+        _pick(lev, level_idx(jnp.real(scaled))),
+        _pick(lev, level_idx(jnp.imag(scaled))),
+    )

@@ -41,6 +41,78 @@ def test_qam_llr_sign_flips_with_noise(qm, rng):
     assert float(jnp.mean(jnp.abs(llr_lo))) > float(jnp.mean(jnp.abs(llr_hi)))
 
 
+def _label_bits(labels, qm):
+    """Labels -> their qm bits each, MSB first, flattened per row."""
+    shifts = np.arange(qm - 1, -1, -1)
+    bits = (labels[..., None] >> shifts) & 1
+    return bits.reshape(labels.shape[:-1] + (-1,)).astype(np.uint8)
+
+
+def _bits_of(x):
+    return np.asarray(x).view(np.uint64)
+
+
+@pytest.mark.parametrize("qm", [2, 4, 6, 8])
+def test_modulate_equals_table_lookup_on_every_label(qm):
+    """Every label of the order maps to its ``constellation`` entry, bit for bit."""
+    table = np.asarray(Q.constellation(qm))
+    labels = np.arange(1 << qm)
+    syms = Q.modulate(jnp.asarray(_label_bits(labels, qm)), qm)
+    assert syms.dtype == jnp.complex64 and syms.shape == labels.shape
+    np.testing.assert_array_equal(_bits_of(syms), _bits_of(table[labels]))
+
+    n = 15900
+    rows = np.stack([np.roll(np.resize(labels, n), r) for r in range(16)])
+    batched = jax.vmap(lambda b: Q.modulate(b, qm))(jnp.asarray(_label_bits(rows, qm)))
+    assert batched.dtype == jnp.complex64 and batched.shape == (16, n)
+    np.testing.assert_array_equal(_bits_of(batched), _bits_of(table[rows]))
+
+
+def _argmin_point(y, qm):
+    table = np.asarray(Q.constellation(qm))
+    d2 = np.abs(y.astype(np.complex128)[..., None] - table.astype(np.complex128)) ** 2
+    return table[np.argmin(d2, axis=-1)]
+
+
+@pytest.mark.parametrize("qm", [2, 4, 6, 8])
+@pytest.mark.parametrize("kind", ["on_grid", "near_grid", "far_outside"])
+def test_nearest_point_equals_bruteforce_argmin(kind, qm):
+    """The per-axis pick is the O(2^qm) argmin over the table, bit for bit."""
+    table = np.asarray(Q.constellation(qm))
+    r = np.random.default_rng(qm)
+    spacing = 2.0 / Q._NORM[qm]  # distance between adjacent per-axis levels
+    if kind == "on_grid":
+        y = table
+    elif kind == "near_grid":
+        # within 0.45 of a spacing of a point per axis: a decision midpoint
+        # lies half a spacing away
+        base = table[r.integers(0, table.size, 4096)]
+        off = r.uniform(-0.45, 0.45, (2, base.size)) * spacing
+        y = (base + off[0] + 1j * off[1]).astype(np.complex64)
+    else:
+        mag = r.uniform(2.0, 50.0, 4096)
+        phase = r.uniform(0.0, 2 * np.pi, 4096)
+        y = (mag * np.exp(1j * phase)).astype(np.complex64)
+    got = Q.nearest_point(jnp.asarray(y), qm)
+    assert got.dtype == jnp.complex64 and got.shape == y.shape
+    np.testing.assert_array_equal(_bits_of(got), _bits_of(_argmin_point(y, qm)))
+
+
+@pytest.mark.parametrize("qm", [2, 4, 6, 8])
+@pytest.mark.parametrize("fn", ["modulate", "nearest_point"])
+def test_qam_lowers_without_gather(fn, qm):
+    """At the 106-PRB slot's (16 UEs, 15900 data REs) neither lookup gathers."""
+    n = 15900
+    if fn == "modulate":
+        f = jax.vmap(lambda b: Q.modulate(b, qm))
+        arg = jax.ShapeDtypeStruct((16, n * qm), jnp.uint8)
+    else:
+        f = jax.vmap(lambda y: Q.nearest_point(y, qm))
+        arg = jax.ShapeDtypeStruct((16, n), jnp.complex64)
+    text = jax.jit(f).lower(arg).as_text()
+    assert "gather" not in text
+
+
 # -- DMRS grid --------------------------------------------------------------------
 
 
