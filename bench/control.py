@@ -69,13 +69,14 @@ def _swap_outputs(ctx, run: dict, precision: str,
     ones with the baseline alone or with MMSE."""
     import numpy as np
 
-    from bench.harness.reference import SlotReference
+    from bench.harness.reference import reference_for
 
     out = {k: np.array(v) for k, v in run["outputs"].items()}
     weights = correct.host_weights(run)
     if fault == "skip_cnn":
         weights = without_cnn(weights)
-    ref = SlotReference(ctx.config, ctx.traffic, ctx.seed, weights, precision)
+    ref = reference_for(ctx.config)(ctx.config, ctx.traffic, ctx.seed,
+                                    weights, precision)
     served_ai = (out["active_mode"] == 0) & (out["gated_overflow"] == 0)
     picks = correct.sample(served_ai, run["window"].first_timed, ctx.seed,
                            ctx.traffic["check_sample_slot_ues"])

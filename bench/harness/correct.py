@@ -1,7 +1,8 @@
 """The correctness check: the timed path's outputs against the reference.
 
-Three layers, each against the plain reference (``reference.py``) or a
-replay written here, never against the program's own code:
+Three layers, each against the plain reference (the one the
+configuration names, ``reference.reference_for``) or a replay written
+here, never against the program's own code:
 
 * the expert bank and everything after it: for a sample of the window's
   slot-UEs, drawn from the seed and split between the two experts, the
@@ -32,7 +33,7 @@ from __future__ import annotations
 import numpy as np
 
 from bench.harness import tree as tree_mod
-from bench.harness.reference import SlotReference
+from bench.harness.reference import reference_for
 
 TB_BAND = 0.05
 TIE_REL = 1e-5
@@ -140,7 +141,7 @@ def gaps(ctx, run: dict, answers: dict | None = None) -> dict:
     first = run["window"].first_timed
     picks = sample(served_ai, first, ctx.seed,
                    ctx.traffic["check_sample_slot_ues"])
-    ref = SlotReference(cfg, ctx.traffic, ctx.seed, host_weights(run))
+    ref = reference_for(cfg)(cfg, ctx.traffic, ctx.seed, host_weights(run))
     snr_gap = {True: [], False: []}
     rsrp_gap = {True: [], False: []}
     mcs_bad = tb_bad = 0

@@ -82,6 +82,7 @@ def build_session(config: dict, traffic: dict, weights, host_policies=None):
     )
 
     spec = CampaignSpec(
+        **radio_kwargs(config, CampaignSpec),
         path="closed_loop",
         scenario=traffic["scenario"],
         scenario_args=tuple(sorted(traffic["scenario_args"].items())),
@@ -104,6 +105,15 @@ def build_session(config: dict, traffic: dict, weights, host_policies=None):
                             host_policies=host_policies)
     check_matches(session, config, traffic)
     return session
+
+
+def radio_kwargs(config: dict, spec_cls) -> dict:
+    """The configuration's receive antennas and layers, as keywords of
+    ``spec_cls`` where it declares them.  A spec that does not runs its
+    own defaults, and ``check_matches`` refuses a configuration that
+    states others."""
+    declared = {f.name for f in dataclasses.fields(spec_cls)}
+    return {k: config[k] for k in ("n_ant", "n_layers") if k in declared}
 
 
 def check_matches(session, config: dict, traffic: dict) -> None:

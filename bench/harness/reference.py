@@ -25,11 +25,33 @@ operand of the estimators' matrix products (LS input and Wiener matrix;
 activations and weights of every convolution) is rounded to symmetric int8,
 or to float8 e4m3, with one scale per tensor, and the products accumulate
 exactly.
+
+**The interface of a reference.**  A configuration names its reference by
+the optional key ``"reference"``: a module of ``bench.harness``, this one
+(``"reference"``) where the key is absent.  ``reference_for`` resolves it;
+the checks (``correct.py``) and the control (``bench/control.py``) take the
+class from there alone.  The module exposes ``SlotReference``, which keeps
+to this interface:
+
+* ``SlotReference(config, traffic, seed, weights, precision="f64")``:
+  the configuration and traffic as loaded from their files, the run's
+  seed, a host copy of the AI expert's parameter tree, and ``"f64"`` or a
+  control's precision (``"int8"``, ``"fp8"``).  It raises where the
+  configuration states what it does not compute;
+* ``slot(slot, ue, reported_snr_db, olla_db, served_by_ai)``: one slot
+  of one UE, as a dict with ``mcs``, ``mcs_input_db``, ``tbs``, ``snr``
+  (dB), ``rsrp``, ``tb_ok``, ``tb_p`` and ``tb_u`` (the transport block's
+  outcome, its probability and its draw);
+* ``olla(tb_ok_history)``: the outer-loop offset (dB) after those outcomes;
+* ``mcs_thresholds()``: the SNR (dB) each MCS needs, in MCS order.
+
+This module computes one layer, and refuses a configuration with more.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib
 
 import numpy as np
 
@@ -76,11 +98,39 @@ def _gold(c_init: int, length: int) -> np.ndarray:
     return (x1[nc:n] + x2[nc:n]) % 2
 
 
+def reference_for(config: dict) -> type:
+    """The ``SlotReference`` class the configuration names (see above).
+
+    Raises ``ValueError``, naming the key, where ``"reference"`` names no
+    module of ``bench.harness`` or one without ``SlotReference``."""
+    name = config.get("reference", "reference")
+    if not (isinstance(name, str) and name.isidentifier()):
+        raise ValueError(f'configuration key "reference": {name!r} is not '
+                         "a module name of bench.harness")
+    module = f"bench.harness.{name}"
+    try:
+        mod = importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(f'configuration key "reference": no module '
+                         f"{module}") from None
+    if not isinstance(getattr(mod, "SlotReference", None), type):
+        raise ValueError(f'configuration key "reference": {module} has no '
+                         "class SlotReference")
+    return mod.SlotReference
+
+
 class SlotReference:
     def __init__(self, config: dict, traffic: dict, seed: int, weights: dict,
                  precision: str = "f64"):
         if precision not in ("f64", "fp8", "int8"):
             raise ValueError(f"precision {precision!r}")
+        if config["n_layers"] != 1:
+            raise ValueError(
+                f"n_layers={config['n_layers']}: this reference computes one "
+                'layer; the configuration has to name, under "reference", '
+                "a reference that computes its layers")
         self.c = config
         self.t = traffic
         self.seed = seed

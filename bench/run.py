@@ -6,9 +6,11 @@ From the root of a checkout.  The cell is an entry of ``workloads`` in
 ``BENCHMARK.json``; everything else is found by name: the configuration
 file it names, ``bench/traffic/<traffic>.json`` (whose ``driver`` is a
 module of ``bench.harness``), ``bench/limits/<cell>.json`` (the limits of
-the correctness check) and one reader ``bench/metrics/<metric>.py`` per
-metric.  ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
-its per-layer metrics from a profiler trace of the window.
+the correctness check), the reference the configuration names under
+``"reference"`` (``bench/harness/reference.py`` where it names none) and
+one reader ``bench/metrics/<metric>.py`` per metric.  ``--trace 0``
+reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
+metrics from a profiler trace of the window.
 
 The run needs TPUs: on any other platform, or with fewer chips than the
 cell asks for, it exits 3 and prints no result.  Detail goes to earlier
@@ -32,6 +34,8 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
+
+from bench.harness.reference import reference_for  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EXIT_NO_CHIP = 3
@@ -125,11 +129,12 @@ def make_context(args, root: str, device_fn=require_chips) -> Context:
         raise KeyError(f"no workload {args.workload!r} (have {sorted(cells)})")
     cell = cells[args.workload]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = load_json(root, conf["file"])
+    reference_for(config)  # an unknown reference fails before the chip
     sys.path.insert(0, os.path.join(root, "src"))
     device = device_fn(cell["chips"])
     return Context(
-        root=root, cell=cell,
-        config=load_json(root, conf["file"]),
+        root=root, cell=cell, config=config,
         traffic=load_json(root, "bench", "traffic", f"{cell['traffic']}.json"),
         limits=load_json(root, "bench", "limits", f"{cell['name']}.json"),
         seed=args.seed, seconds=args.seconds, trace=bool(args.trace),

@@ -20,9 +20,9 @@ def cpu_device(n: int) -> dict:
 
 
 def make_root(tmp: str, *, limits: dict | None = None, n_prb: int = 8,
-              n_ues: int = 4, expert: tuple = (4, 1)) -> str:
+              n_ues: int = 4, expert: tuple = (4, 1), n_ant: int = 4) -> str:
     """``expert``: the AI expert's channels and residual blocks (the x5g
-    configuration's are (32, 4))."""
+    configuration's are (32, 4)); ``n_ant``: the receive antennas."""
     root = os.path.join(tmp, "checkout")
     bench = os.path.join(root, "bench")
     for sub in ("metrics", "work", "traffic", "limits", "configs"):
@@ -33,7 +33,7 @@ def make_root(tmp: str, *, limits: dict | None = None, n_prb: int = 8,
     peaks["devices"]["cpu"] = dict(peaks["devices"]["TPU v5 lite"])
     json.dump(peaks, open(os.path.join(bench, "peaks.json"), "w"))
     cfg = json.load(open(os.path.join(bench, "configs", "x5g_106prb.json")))
-    cfg.update(name="tiny", n_prb=n_prb, n_ues=n_ues,
+    cfg.update(name="tiny", n_prb=n_prb, n_ues=n_ues, n_ant=n_ant,
                gated_capacity=n_ues // 2, ai_channels=expert[0],
                ai_res_blocks=expert[1])
     json.dump(cfg, open(os.path.join(bench, "configs", "tiny.json"), "w"))

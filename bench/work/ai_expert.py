@@ -1,6 +1,7 @@
 """Work of the AI expert (residual CNN channel estimator) from shapes.
 
-Per UE and receive port, 2 flops per multiply-add of the 3x3 ('same')
+Per UE, receive port and layer (one estimate each, over the
+configuration's pilot comb), 2 flops per multiply-add of the 3x3 ('same')
 convolutions: a stem from 2 to C channels and R residual blocks of two
 C -> C convolutions on the pilot grid (Np x S), a C -> 2C up-projection on
 the pilot grid, a sub-pixel shuffle to C channels on the full grid
@@ -19,6 +20,11 @@ def _dims(config: dict):
     return kh * kw, config["ai_channels"], (n_sc // 2) * s, n_sc * s
 
 
+def _ports(config: dict) -> int:
+    """Channel estimates per UE: receive ports x layers."""
+    return config["n_ant"] * config.get("n_layers", 1)
+
+
 def flops_per_ue(config: dict) -> float:
     k, c, hw_in, hw_out = _dims(config)
     per_port = (
@@ -27,9 +33,9 @@ def flops_per_ue(config: dict) -> float:
         + 2 * k * c * (2 * c) * hw_in
         + 2 * k * c * 2 * hw_out
     )
-    return float(config["n_ant"] * per_port)
+    return float(_ports(config) * per_port)
 
 
 def head_overcount(config: dict) -> float:
     k, c, _, hw_out = _dims(config)
-    return float(config["n_ant"] * 2 * k * c * 2 * hw_out)
+    return float(_ports(config) * 2 * k * c * 2 * hw_out)

@@ -3,13 +3,19 @@
 One call interpolates ``B`` pilot vectors of ``Np`` complex values to
 ``Nsc`` subcarriers: ``out (B, Nsc) = H (B, Np) @ W (Np, Nsc)``, complex,
 held as f32 real and imaginary planes.  ``B`` is UEs x receive ports x
-DMRS symbols.  Unpadded shapes throughout.
+layers x DMRS symbols: one estimate per receive port and layer, over the
+configuration's pilot comb.  Unpadded shapes throughout.
 """
+
+
+def _ports(config: dict) -> int:
+    """Channel estimates per UE: receive ports x layers."""
+    return config["n_ant"] * config.get("n_layers", 1)
 
 
 def shapes(config: dict) -> tuple:
     n_sc = 12 * config["n_prb"]
-    b = config["n_ues"] * config["n_ant"] * len(config["dmrs_symbols"])
+    b = config["n_ues"] * _ports(config) * len(config["dmrs_symbols"])
     return b, n_sc // 2, n_sc
 
 
@@ -28,4 +34,4 @@ def expert_flops_per_ue(config: dict) -> float:
     (``repro.phy.estimators.estimator_flops``) states it: a four-product
     complex matmul, 8 real flops per complex multiply-add."""
     _, n_p, n_sc = shapes(config)
-    return 8.0 * config["n_ant"] * len(config["dmrs_symbols"]) * n_p * n_sc
+    return 8.0 * _ports(config) * len(config["dmrs_symbols"]) * n_p * n_sc
