@@ -261,6 +261,10 @@ class CampaignSpec:
     n_ues: int = 4
     n_slots: int = 30
     n_prb: int = 24
+    # the radio: gNB receive antennas and the UE's uplink layers (1 or 2:
+    # DMRS ports 0-1 of CDM group 0, repro.phy.dmrs)
+    n_ant: int = 4
+    n_layers: int = 1
     seed: int = 0
     modes: Any = 1
     bank: ExpertBankSpec = dataclasses.field(default_factory=ExpertBankSpec)
@@ -306,6 +310,18 @@ class CampaignSpec:
                 object.__setattr__(self, name, _tuplify(v))
         if self.n_ues < 1 or self.n_slots < 1:
             raise ValueError("n_ues and n_slots must be >= 1")
+        if self.n_ant < 1:
+            raise ValueError(f"n_ant={self.n_ant}: must be >= 1")
+        if self.n_layers not in (1, 2):
+            raise ValueError(
+                f"n_layers={self.n_layers}: one of 1, 2 (more layers need "
+                "DMRS CDM group 1, which is not built)"
+            )
+        if self.n_layers > self.n_ant:
+            raise ValueError(
+                f"n_layers={self.n_layers} exceeds n_ant={self.n_ant}: the "
+                "receiver cannot separate more layers than it has antennas"
+            )
         for k, _ in self.scenario_args:
             if not isinstance(k, str):
                 raise ValueError("scenario_args must be (name, value) pairs")
@@ -536,7 +552,9 @@ class ArchesSession:
             else None
         )
         self._validate()
-        self.cfg = SlotConfig(n_prb=spec.n_prb)
+        self.cfg = SlotConfig(
+            n_prb=spec.n_prb, n_ant=spec.n_ant, n_layers=spec.n_layers
+        )
         scenario = get_scenario(spec.scenario)
         # streaming campaigns instantiate per-UE scenarios over the
         # *stable-id* universe: channel conditions follow the UE identity,

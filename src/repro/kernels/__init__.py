@@ -3,3 +3,4 @@
 #   switch_select — the paper's CUDA switch kernel (zero-gap output selection)
 #   mmse_interp   — MMSE/Wiener frequency-domain interpolation (MXU matmul)
 #   tree_infer    — vectorized decision-tree policy inference
+#   lmmse_detect  — per-RE LMMSE detection of a multi-layer uplink (VPU)

@@ -40,7 +40,8 @@ class AiEstimatorConfig:
     kernel_hw: tuple[int, int] = (3, 3)
 
     def flops(self, cfg: SlotConfig) -> float:
-        """Conv MACs x2, all blocks, all antennas (cost-model input)."""
+        """Conv MACs x2, all blocks, one image per receive antenna and
+        layer (cost-model input)."""
         kh, kw = self.kernel_hw
         hw_in = cfg.n_pilot_sc * cfg.n_dmrs_sym
         hw_out = cfg.n_sc * cfg.n_dmrs_sym
@@ -51,7 +52,7 @@ class AiEstimatorConfig:
             + 2 * kh * kw * c * (2 * c) * hw_in  # up-projection
             + 2 * kh * kw * (2 * c) * 2 * hw_out  # head (on upsampled grid)
         )
-        return float(cfg.n_ant * per_ant)
+        return float(cfg.n_ant * cfg.n_layers * per_ant)
 
 
 def _conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:

@@ -4,7 +4,9 @@ The estimator experts produce full-band estimates at the N_sym^DMRS pilot
 symbols only; the equalizer (i) interpolates across all 14 OFDM symbols in
 time — the division of labour the paper describes for Aerial — then
 (ii) performs per-RE MRC/MMSE combining across receive antennas and
-(iii) reports post-equalization SINR, which feeds the SNR KPM.
+(iii) reports post-equalization SINR, which feeds the SNR KPM.  With two
+layers, ``lmmse_equalize`` separates the layers per RE (LMMSE, the
+``lmmse_detect`` kernel); one layer keeps ``mmse_equalize``.
 """
 
 from __future__ import annotations
@@ -15,7 +17,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.lmmse_detect import lmmse_detect
 from repro.phy.nr import SlotConfig
+
+
+def time_weights(cfg: SlotConfig) -> np.ndarray:
+    """Piecewise-linear interpolation weights across OFDM symbols,
+    (n_sym, n_dmrs_sym) float64: symbol ``m`` takes ``w[m] @ h_dmrs`` of
+    its two neighbouring DMRS symbols (edge symbols clamped)."""
+    sym = np.arange(cfg.n_sym, dtype=np.float64)
+    anchors = np.asarray(cfg.dmrs_symbols, np.float64)
+    w = np.zeros((cfg.n_sym, cfg.n_dmrs_sym))
+    for i, s in enumerate(sym):
+        j = int(np.clip(np.searchsorted(anchors, s) - 1, 0, len(anchors) - 2))
+        t0, t1 = anchors[j], anchors[j + 1]
+        a = np.clip((s - t0) / (t1 - t0), 0.0, 1.0)
+        w[i, j] = 1.0 - a
+        w[i, j + 1] = a
+    return w
 
 
 def time_interpolate(cfg: SlotConfig, h_dmrs: jax.Array) -> jax.Array:
@@ -24,17 +43,7 @@ def time_interpolate(cfg: SlotConfig, h_dmrs: jax.Array) -> jax.Array:
     ``h_dmrs`` (..., n_sc, n_dmrs_sym) at symbols ``cfg.dmrs_symbols``
     -> (..., n_sc, n_sym) over the whole slot (edge symbols clamped).
     """
-    sym = np.arange(cfg.n_sym, dtype=np.float64)
-    anchors = np.asarray(cfg.dmrs_symbols, np.float64)
-    # piecewise-linear weights, host-precomputed: (n_sym, n_dmrs_sym)
-    w = np.zeros((cfg.n_sym, cfg.n_dmrs_sym))
-    for i, s in enumerate(sym):
-        j = int(np.clip(np.searchsorted(anchors, s) - 1, 0, len(anchors) - 2))
-        t0, t1 = anchors[j], anchors[j + 1]
-        a = np.clip((s - t0) / (t1 - t0), 0.0, 1.0)
-        w[i, j] = 1.0 - a
-        w[i, j + 1] = a
-    wj = jnp.asarray(w, jnp.float32)
+    wj = jnp.asarray(time_weights(cfg), jnp.float32)
     return jnp.einsum("...sd,md->...sm", h_dmrs, wj.astype(h_dmrs.dtype))
 
 
@@ -65,6 +74,30 @@ def mmse_equalize(
     # what degrades when the estimate is bad
     sinr = den / jnp.maximum(noise_var, 1e-12)
     return x_hat, sinr
+
+
+def lmmse_equalize(
+    cfg: SlotConfig,
+    rx_grid: jax.Array,
+    h_est_dmrs: jax.Array,
+    noise_var: jax.Array,
+) -> jax.Array:
+    """Per-RE LMMSE detection of two layers, for a batch of UEs (the
+    ``lmmse_detect`` kernel, with this slot's interpolation across symbols).
+
+    Args:
+      rx_grid: (U, n_ant, n_sc, n_sym) received grids.
+      h_est_dmrs: (U, n_ant, 2, n_sc, n_dmrs_sym) expert outputs.
+      noise_var: (U,) noise variances.
+
+    Returns:
+      (U, 2, n_sc, n_sym) detected symbols.  With the second layer's
+      channel zero, layer 0 is ``mmse_equalize``'s, up to f32 rounding.
+    """
+    return lmmse_detect(
+        h_est_dmrs, rx_grid, noise_var,
+        jnp.asarray(time_weights(cfg), jnp.float32),
+    )
 
 
 def effective_noise_var(sinr: jax.Array) -> jax.Array:

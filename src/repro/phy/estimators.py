@@ -33,9 +33,23 @@ def ls_estimate(
 
     ``rx_grid`` (n_ant, n_sc, n_sym), ``pilots`` (n_dmrs_sym, n_pilot_sc)
     -> (n_ant, n_dmrs_sym, n_pilot_sc).
+
+    With more than one layer, -> (n_ant, n_layers, n_dmrs_sym, n_pilot_sc):
+    each pilot pair ``(2m, 2m+1)`` of the comb is de-spread with each
+    port's OCC (``dmrs.port_occ``), and the pair's estimate is given to
+    both of its pilots, so each port keeps the comb's shape.  A port's
+    estimate is of its precoded channel (TPMI 0's ``1 / sqrt(n_layers)``
+    included), the channel its layer's unit-power symbols see.
     """
     rx_pilots = dmrs_mod.extract_pilot_re(cfg, rx_grid)
-    return rx_pilots * jnp.conj(pilots) / (jnp.abs(pilots) ** 2 + 1e-12)
+    h = rx_pilots * jnp.conj(pilots) / (jnp.abs(pilots) ** 2 + 1e-12)
+    if cfg.n_layers == 1:
+        return h
+    occ = dmrs_mod.port_occ(cfg).astype(np.float32)  # (n_l, n_pilot)
+    pairs = (h[..., None, :, :] * occ[:, None, :]).reshape(
+        h.shape[:-2] + (cfg.n_layers, cfg.n_dmrs_sym, -1, 2)
+    )
+    return jnp.repeat(jnp.mean(pairs, axis=-1), 2, axis=-1)
 
 
 def exponential_pdp_correlation(
@@ -94,6 +108,7 @@ def mmse_estimate(
 
 
 def estimator_flops(cfg: SlotConfig) -> float:
-    """Complex-matmul FLOPs for the Wiener interpolation (cost model)."""
-    b = cfg.n_ant * cfg.n_dmrs_sym
+    """Complex-matmul FLOPs for the Wiener interpolation (cost model): one
+    interpolation per receive antenna and layer."""
+    b = cfg.n_ant * cfg.n_layers * cfg.n_dmrs_sym
     return 8.0 * b * cfg.n_pilot_sc * cfg.n_sc

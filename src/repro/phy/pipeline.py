@@ -8,6 +8,16 @@ Per slot:
        -> time-interp + MMSE equalizer -> max-log LLRs -> TB CRC (MIESM)
   KPM  Aerial Data Lake (PHY, per-slot) + OAI (L2+) telemetry
 
+With ``SlotConfig.n_layers == 2`` a UE sends two layers of one codeword
+(TPMI 0, DMRS ports 0 and 1 told apart by their OCC, ``repro.phy.dmrs``).
+LS is per port; the bank sees each (antenna, layer) estimate as one port
+of an ``n_ant * n_layers``-port UE (the layer axis is folded into the
+antenna axis before the bank and unfolded after it), so both experts and
+the switch run unchanged and one expert serves all of a UE's ports.  A
+per-RE LMMSE detector (the ``lmmse_detect`` kernel) separates the layers
+in place of MRC.  The TBS takes both layers, and the SNR, MIESM and RSRP
+KPMs are taken over both.
+
 Mode numbering follows the paper: ``mode=0`` selects AI (designated buffer —
 switch is a no-op), ``mode=1`` selects MMSE (copy path).
 
@@ -53,7 +63,12 @@ from repro.phy.channel import (
     simulate_slot_channel,
     simulate_slot_channel_traced,
 )
-from repro.phy.equalizer import effective_noise_var, mmse_equalize, mmse_irc_equalize
+from repro.phy.equalizer import (
+    effective_noise_var,
+    lmmse_equalize,
+    mmse_equalize,
+    mmse_irc_equalize,
+)
 from repro.phy.estimators import (
     WienerInterpolator,
     estimator_flops,
@@ -162,18 +177,31 @@ class PuschPipeline:
         h_full = mmse_interp(h_ls, self.interpolator.w)
         return jnp.moveaxis(h_full, -2, -1)[:, None]
 
+    def _unfold(self, h: jax.Array) -> jax.Array:
+        """An expert's estimate on the folded (antenna, layer) ports,
+        (n_ant * n_layers, 1, n_sc, n_dmrs_sym), as (n_ant, n_layers, n_sc,
+        n_dmrs_sym) (see the module docstring)."""
+        return h.reshape((self.cfg.n_ant, self.cfg.n_layers) + h.shape[2:])
+
     # -- jitted slot stages ----------------------------------------------------
 
     @partial(jax.jit, static_argnames=("self", "qm", "tbs_bits"))
     def _tx_slot(self, key: jax.Array, qm: int, tbs_bits: int):
-        """bits -> QAM symbols -> resource grid (+ pilots)."""
+        """bits -> QAM symbols -> resource grid (+ pilots).  With more than
+        one layer the payload is drawn as the batched engine draws it
+        (``BatchedPuschPipeline._ue_pre``), one row a layer."""
         cfg = self.cfg
         n_coded = cfg.n_data_re() * qm
-        bits = jax.random.bernoulli(key, 0.5, (n_coded,)).astype(jnp.uint8)
+        if cfg.n_layers == 1:
+            bits = jax.random.bernoulli(key, 0.5, (n_coded,)).astype(jnp.uint8)
+        else:
+            bits = jax.random.bernoulli(
+                key, 0.5, (cfg.n_layers, cfg.n_data_re() * max(QM_VALUES))
+            ).astype(jnp.uint8)[:, :n_coded]
         syms = qam.modulate(bits, qm)
         pilots = dmrs_mod.dmrs_sequence(cfg)
         grid = dmrs_mod.map_slot_grid(cfg, syms, pilots)
-        return bits, grid, pilots
+        return bits, syms, grid, pilots
 
     @partial(jax.jit, static_argnames=("self", "qm", "perturb"))
     def _rx_slot(
@@ -207,37 +235,49 @@ class PuschPipeline:
         """
         cfg = self.cfg
         h_ls = ls_estimate(cfg, rx_grid, pilots)
+        ports = h_ls.reshape((-1,) + h_ls.shape[-2:])
         if perturb:
             # Methodology stage 1 (paper Fig. 3): MMSE only, AWGN injected at
             # node 2c — no switching, no AI in the loop.
-            h_sel = self._mmse_from_ls(h_ls)
+            h_sel = self._unfold(self._mmse_from_ls(ports))
             h_sel = perturb_estimate(h_sel, rho, perturb_key)
             all_outputs = None
         else:
-            out = self.bank(mode, h_ls)
-            h_sel = out.selected
-            all_outputs = out.all_outputs
-        x_hat, _ = mmse_equalize(cfg, rx_grid, h_sel, noise_var)
+            out = self.bank(mode, ports)
+            h_sel = self._unfold(out.selected)
+            all_outputs = None if out.all_outputs is None else tuple(
+                self._unfold(h) for h in out.all_outputs)
+        if cfg.n_layers == 1:
+            x_hat, _ = mmse_equalize(cfg, rx_grid, h_sel, noise_var)
+            data_hat = dmrs_mod.extract_data_re(cfg, x_hat[None])[0]
+        else:
+            x_hat = lmmse_equalize(
+                cfg, rx_grid[None], h_sel[None], jnp.reshape(noise_var, (1,))
+            )
+            # (n_layers, n_data_re)
+            data_hat = dmrs_mod.extract_data_re(cfg, x_hat[0])
 
-        # measured SINR: decision-directed EVM on data REs (receiver-side)
-        data_hat = dmrs_mod.extract_data_re(cfg, x_hat[None])[0]
+        # measured SINR: decision-directed EVM on data REs of every layer
+        # (receiver-side)
         points = qam.constellation(qm)
         nearest = points[
-            jnp.argmin(jnp.abs(data_hat[:, None] - points[None, :]), axis=1)
+            jnp.argmin(jnp.abs(data_hat[..., None] - points), axis=-1)
         ]
         dd_err = jnp.mean(jnp.abs(data_hat - nearest) ** 2)
         sig_pow = jnp.mean(jnp.abs(nearest) ** 2)
         sinr_meas = sig_pow / jnp.maximum(dd_err, 1e-9)
 
         # genie per-RE SINR on data REs (TB-success model only)
-        data_x = dmrs_mod.extract_data_re(cfg, x_hat[None])[0]
-        genie_err = jnp.abs(data_x - tx_data_syms) ** 2
-        # smooth over PRB-sized windows: LDPC averages error bursts
-        n = genie_err.shape[0] - genie_err.shape[0] % 12
-        smoothed = jnp.mean(genie_err[:n].reshape(-1, 12), axis=1)
+        genie_err = jnp.abs(data_hat - tx_data_syms) ** 2
+        # smooth over PRB-sized windows of each layer: LDPC averages error
+        # bursts
+        n = genie_err.shape[-1] - genie_err.shape[-1] % 12
+        smoothed = jnp.mean(
+            genie_err[..., :n].reshape(genie_err.shape[:-1] + (-1, 12)), axis=-1
+        ).reshape(-1)
         genie_sinr = 1.0 / jnp.maximum(smoothed, 1e-9)
 
-        llr = qam.demap_llr(data_x, 1.0 / sinr_meas, qm)
+        llr = qam.demap_llr(data_hat, 1.0 / sinr_meas, qm)
         rsrp = jnp.mean(jnp.abs(h_sel) ** 2)
         return {
             "h_selected": h_sel,
@@ -260,18 +300,37 @@ class PuschPipeline:
         perturb_rho: float | None = None,
     ) -> tuple[LinkState, dict[str, Any], dict[str, Mapping[str, float]]]:
         """Execute one slot; returns (new link state, outputs, KPMs-by-source)."""
+        return self.run_slot_keys(
+            jax.random.split(key, 5), mode, link, channel_cfg,
+            perturb_rho=perturb_rho,
+        )
+
+    def run_slot_keys(
+        self,
+        keys,
+        mode: int | jax.Array,
+        link: LinkState,
+        channel_cfg: ChannelConfig,
+        *,
+        perturb_rho: float | None = None,
+    ) -> tuple[LinkState, dict[str, Any], dict[str, Mapping[str, float]]]:
+        """``run_slot`` with its draws' keys given: ``(k_tx, k_ch, k_n,
+        k_crc, k_p)`` for the payload, channel, noise, TB decoding draw and
+        the perturbation (``k_p`` only read with ``perturb_rho``).  With the
+        batched engine's four keys of a UE's slot (``BatchedPuschPipeline.
+        _ue_pre``) and more than one layer, the host slot draws what the
+        batched one draws."""
         cfg = self.cfg
-        k_tx, k_ch, k_n, k_crc, k_p = jax.random.split(key, 5)
+        k_tx, k_ch, k_n, k_crc, k_p = keys
 
         # link adaptation from last slot's report + OLLA offset (L2 behaviour)
         mcs = select_mcs(link.reported_snr_db + link.olla_offset_db)
-        tbs = transport_block_size(cfg.n_data_re(), mcs)
-        bits, tx_grid, pilots = self._tx_slot(k_tx, mcs.qm, tbs)
+        tbs = transport_block_size(cfg.n_data_re(), mcs, cfg.n_layers)
+        bits, tx_syms, tx_grid, pilots = self._tx_slot(k_tx, mcs.qm, tbs)
 
         fields = simulate_slot_channel(k_ch, cfg, channel_cfg)
         rx_grid = apply_channel(k_n, tx_grid, fields)
 
-        tx_syms = dmrs_mod.extract_data_re(cfg, tx_grid[0][None])[0]
         rx = self._rx_slot(
             jnp.asarray(mode, jnp.int32),
             rx_grid,
@@ -501,8 +560,10 @@ class BatchedPuschPipeline:
             cfg, rms_delay_spread_s=rms_delay_spread_s
         )
         self._pilots = dmrs_mod.dmrs_sequence(cfg)
-        self._tbs_table = jnp.asarray(tbs_table(cfg.n_data_re()))
-        self._ncb_table = jnp.asarray(n_code_blocks_table(cfg.n_data_re()))
+        self._tbs_table = jnp.asarray(tbs_table(cfg.n_data_re(), cfg.n_layers))
+        self._ncb_table = jnp.asarray(
+            n_code_blocks_table(cfg.n_data_re(), cfg.n_layers)
+        )
         self._qm_by_mcs = jnp.asarray(QM_BY_MCS)
         self._qm_idx_by_mcs = jnp.asarray(QM_INDEX_BY_MCS)
         self._rate_by_mcs = jnp.asarray(RATE_BY_MCS)
@@ -564,7 +625,24 @@ class BatchedPuschPipeline:
     # -- per-UE stages (vmapped inside slot_step) -----------------------------
 
     def _ue_pre(self, profile: TdlProfile, p: ChannelParams, snr_db, olla_db, key):
-        """Link adaptation + TX + channel + LS for one UE (traced MCS)."""
+        """Link adaptation + TX + channel + LS for one UE (traced MCS).
+
+        The draws, from the UE's slot key ``key`` (``fold_in(fold_in(root,
+        u), s)`` for UE ``u`` in slot ``s``), split four ways into ``k_tx,
+        k_ch, k_n, k_crc``:
+
+        * ``k_tx``: payload bits, ``bernoulli(k_tx, 0.5, shape)`` with shape
+          ``(n_data_re * 8,)``, or ``(n_layers, n_data_re * 8)`` with more
+          than one layer; at modulation order ``q`` a layer takes the first
+          ``n_data_re * q`` bits of its row;
+        * ``k_ch``: the channel (``simulate_slot_channel_traced``): split
+          three ways into the UE's TDL response, drawn for shape
+          ``(n_ant, n_layers, taps)``, the interference symbols and the
+          interferer's response, drawn for the same shape of which layer 0
+          is kept;
+        * ``k_n``: the receiver noise;
+        * ``k_crc``: the transport block's decoding draw.
+        """
         cfg = self.cfg
         with tracing.stage(tracing.TX):
             k_tx, k_ch, k_n, k_crc = jax.random.split(key, 4)
@@ -579,11 +657,13 @@ class BatchedPuschPipeline:
             # are drawn once at the widest order and prefix-sliced, so the
             # payload for a given (key, qm) is deterministic.
             n_re = cfg.n_data_re()
-            bits = jax.random.bernoulli(
-                k_tx, 0.5, (n_re * max(QM_VALUES),)
-            ).astype(jnp.uint8)
+            shape = (n_re * max(QM_VALUES),)
+            if cfg.n_layers > 1:
+                shape = (cfg.n_layers,) + shape
+            bits = jax.random.bernoulli(k_tx, 0.5, shape).astype(jnp.uint8)
             syms_all = jnp.stack(
-                [qam.modulate(bits[: n_re * q], q) for q in QM_VALUES], axis=0
+                [qam.modulate(bits[..., : n_re * q], q) for q in QM_VALUES],
+                axis=0,
             )
             syms = jnp.take(syms_all, qm_idx, axis=0)
             tx_grid = dmrs_mod.map_slot_grid(cfg, syms, self._pilots)
@@ -614,6 +694,18 @@ class BatchedPuschPipeline:
         with tracing.stage(tracing.KPM):
             return self._ue_kpms(link, pre, h_sel, data_hat)
 
+    def _post_layers(self, link: DeviceLinkState, pre: dict, h_sel: jax.Array):
+        """``_ue_post`` for every UE at once with more than one layer: the
+        ``lmmse_detect`` kernel runs over the whole UE batch."""
+        cfg = self.cfg
+        with tracing.stage(tracing.RX):
+            x_hat = lmmse_equalize(
+                cfg, pre["rx_grid"], h_sel, pre["noise_var"]
+            )
+            data_hat = dmrs_mod.extract_data_re(cfg, x_hat)  # (U, l, n_re)
+        with tracing.stage(tracing.KPM):
+            return jax.vmap(self._ue_kpms)(link, pre, h_sel, data_hat)
+
     def _ue_kpms(self, link: DeviceLinkState, pre: dict, h_sel, data_hat):
         """EVM, TB model, OLLA and the KPM report for one UE."""
         cfg = self.cfg
@@ -630,10 +722,13 @@ class BatchedPuschPipeline:
         sig_pow = jnp.take(jnp.stack(sig_pows), pre["qm_idx"])
         sinr_meas = sig_pow / jnp.maximum(dd_err, 1e-9)
 
-        # genie per-RE SINR (MIESM TB model), as in the host pipeline
+        # genie per-RE SINR (MIESM TB model), as in the host pipeline; with
+        # more than one layer, PRB windows of each layer, pooled
         genie_err = jnp.abs(data_hat - pre["syms"]) ** 2
-        n = genie_err.shape[0] - genie_err.shape[0] % 12
-        smoothed = jnp.mean(genie_err[:n].reshape(-1, 12), axis=1)
+        n = genie_err.shape[-1] - genie_err.shape[-1] % 12
+        smoothed = jnp.mean(
+            genie_err[..., :n].reshape(genie_err.shape[:-1] + (-1, 12)), axis=-1
+        ).reshape(-1)
         genie_sinr = 1.0 / jnp.maximum(smoothed, 1e-9)
 
         ok = tb_success_dynamic(
@@ -811,10 +906,20 @@ class BatchedPuschPipeline:
                 lambda snr, olla, key: self._ue_pre(profile, p, snr, olla, key)
             )(link.reported_snr_db, link.olla_offset_db, keys)
         n_ues = keys.shape[0]
+        cfg = self.cfg
         with tracing.stage(tracing.EXPERTS):
-            h_sel, extras = self._bank_core(pre["h_ls"], modes, keys, n_ues,
+            # (antenna, layer) ports folded into the antenna axis and back;
+            # both reshapes are the identity at one layer
+            h_ls = pre["h_ls"].reshape((n_ues, -1) + pre["h_ls"].shape[-2:])
+            h_sel, extras = self._bank_core(h_ls, modes, keys, n_ues,
                                             rho, faults, corrupt)
-        new_link, outputs = jax.vmap(self._ue_post)(link, pre, h_sel)
+            h_sel = h_sel.reshape(
+                (n_ues, cfg.n_ant, cfg.n_layers) + h_sel.shape[3:]
+            )
+        if cfg.n_layers == 1:
+            new_link, outputs = jax.vmap(self._ue_post)(link, pre, h_sel)
+        else:
+            new_link, outputs = self._post_layers(link, pre, h_sel)
         outputs.update(extras)
         if active is not None:
             # detached lanes: state frozen, every output/KPM leaf zeroed —

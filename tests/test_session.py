@@ -807,6 +807,8 @@ def _hash_completeness_case():
         ("CampaignSpec", "n_ues"): 6,
         ("CampaignSpec", "n_slots"): 12,
         ("CampaignSpec", "n_prb"): 12,
+        ("CampaignSpec", "n_ant"): 2,
+        ("CampaignSpec", "n_layers"): 2,
         ("CampaignSpec", "seed"): 1,
         ("CampaignSpec", "modes"): 0,
         ("CampaignSpec", "bank"): ExpertBankSpec(),

@@ -28,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.gated_expert import gated_expert_apply
+from repro.kernels.lmmse_detect import lmmse_detect
 from repro.kernels.mmse_interp import mmse_interp
 from repro.kernels.switch_select.ops import (
     switch_gather_batched_leaf,
@@ -35,6 +36,7 @@ from repro.kernels.switch_select.ops import (
 )
 from repro.kernels.tree_infer import pack_tree, tree_infer
 from repro.phy.ai_estimator import AiEstimatorConfig, fold_ai_params, init_params
+from repro.phy.equalizer import time_weights
 from repro.phy.nr import SlotConfig
 
 N_UES = 16
@@ -123,12 +125,24 @@ def _gated_expert(cfg, s):
     )
 
 
+def _lmmse(cfg, s):
+    """Two layers, the cell's 4 RX, at the carrier's width."""
+    tw = jnp.asarray(time_weights(cfg), jnp.float32)
+    return _compiled_text(
+        lambda h, y, nv: lmmse_detect(h, y, nv, tw, interpret=False),
+        s((N_UES, cfg.n_ant, 2, cfg.n_sc, cfg.n_dmrs_sym), jnp.complex64),
+        s((N_UES, cfg.n_ant, cfg.n_sc, cfg.n_sym), jnp.complex64),
+        s((N_UES,), jnp.float32),
+    )
+
+
 KERNELS = {
     "mmse_interp": _mmse,
     "switch_select_batched_leaf": _switch_select,
     "switch_gather_batched_leaf": _switch_gather,
     "tree_infer": _tree,
     "gated_expert_fused": _gated_expert,
+    "lmmse_detect": _lmmse,
 }
 
 
@@ -140,20 +154,12 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, n_prb):
     assert "tpu_custom_call" in text, f"{kernel} lowered without a Mosaic kernel"
 
 
-@pytest.fixture(scope="module")
-def slot_step_text(one_chip):
-    """The closed-loop slot step (the program the benchmark drives),
-    compiled for one v5e chip at 24 PRB and 4 UEs.  ``jax.default_backend``
-    is steered to ``"tpu"`` while it is traced, so that the step takes its
-    Pallas kernels as it does on the chip.  JAX's trace caches are cleared
-    around it: a kernel traced earlier in this process for the CPU would
-    be reused in interpret mode, and this trace must not be reused by a
-    later CPU test."""
+def _slot_step_text(one_chip, cfg):
     from test_tracing import _slot, step_inputs
 
     from repro.phy import pipeline
 
-    x = step_inputs(SlotConfig(n_prb=24), n_ues=4)
+    x = step_inputs(cfg, n_ues=4)
     spec = lambda tree: jax.tree.map(  # noqa: E731
         lambda v: _spec(one_chip, np.shape(v), jnp.asarray(v).dtype), tree)
     args = [spec(a) if i not in (0, 1) else a
@@ -166,6 +172,41 @@ def slot_step_text(one_chip):
     finally:
         jax.clear_caches()
     return lowered.compile().as_text()
+
+
+def _kernel_names(text):
+    return re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*"
+                      r'custom_call_target="tpu_custom_call"',
+                      text, flags=re.M)
+
+
+def test_two_layer_slot_step_compiles_with_its_detector_for_v5e(one_chip):
+    """The two-layer closed-loop step (24 PRB, 4 UEs, 4 RX) compiles for a
+    v5e with the ``lmmse_detect`` kernel under its own name, beside the
+    bank's kernels, and keeps every stage scope."""
+    from repro import tracing
+
+    text = _slot_step_text(one_chip, SlotConfig(n_prb=24, n_layers=2))
+    kernels = _kernel_names(text)
+    for name in ("lmmse_detect", "mmse_interp", "tree_infer"):
+        assert any(k == name or k.startswith(name + ".") for k in kernels), (
+            name, kernels)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for stage in tracing.STAGES:
+        assert any(re.search(rf"(?<![\w.]){re.escape(stage)}(?![\w.])", n)
+                   for n in names), stage
+
+
+@pytest.fixture(scope="module")
+def slot_step_text(one_chip):
+    """The closed-loop slot step (the program the benchmark drives),
+    compiled for one v5e chip at 24 PRB and 4 UEs.  ``jax.default_backend``
+    is steered to ``"tpu"`` while it is traced, so that the step takes its
+    Pallas kernels as it does on the chip.  JAX's trace caches are cleared
+    around it: a kernel traced earlier in this process for the CPU would
+    be reused in interpret mode, and this trace must not be reused by a
+    later CPU test."""
+    return _slot_step_text(one_chip, SlotConfig(n_prb=24))
 
 
 def test_slot_step_keeps_its_kernel_names_for_v5e(slot_step_text):
