@@ -23,7 +23,7 @@ CHANNEL = "arches.channel"  # fading, interference, noise, cell coupling
 RX = "arches.rx"  # LS estimate, equalizer, data-RE extraction
 EXPERTS = "arches.experts"  # the expert bank and its switch
 KPM = "arches.kpm"  # EVM, TB model, OLLA, the KPM report
-DECIDE = "arches.decide"  # KPM window, policy, switch register, breaker
+DECIDE = "arches.decide"  # KPM window, policy, register, breaker, packing
 STAGES = (TX, CHANNEL, RX, EXPERTS, KPM, DECIDE)
 
 #: host span around one call of the compiled closed-loop slot step

@@ -7,8 +7,9 @@
   call tree from the entry computation, as XLA's inliner does.
 * ``_closed_slot_step``, now a Python method that opens the
   ``arches.slot.dispatch`` span around the compiled step, still compiles
-  once and computes what the closed-loop scan computes, bit for bit, and
-  shows one span per call while a profiler session is active.
+  once and computes what the closed-loop scan computes, bit for bit (read
+  through the paths of its ``SlotOutputs`` view), and shows one span per
+  call while a profiler session is active.
 * ``run_streaming``'s phase timers fill the same ``stats`` keys.
 
 The step compiled for a TPU v5e (its Pallas kernels' names, its scopes) is
@@ -204,7 +205,7 @@ def test_slot_step_wrapper_compiles_once_and_matches_the_scan(step):
         x["policy"],
     )
     for s, out in enumerate(outs):
-        got = jax.tree_util.tree_leaves_with_path(out)
+        got = jax.tree_util.tree_leaves_with_path(out.to_dict())
         want = jax.tree.leaves(jax.tree.map(lambda v: v[s], traj))
         assert len(got) == len(want)
         for (path, a), b in zip(got, want):
